@@ -20,9 +20,9 @@ BASE = [sys.executable, "-m", "repro.launch.serve", "--arch", "paper-demo",
         "--smoke", "--trace", "poisson", "--verify"]
 SAMPLED = ["--temperature", "0.8", "--top-k", "40", "--top-p", "0.95",
            "--sched", "edf", "--deadline", "2.0"]
-ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
-       # containers with libtpu probe TPU metadata forever otherwise
-       "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
+# the environment passes through unchanged: the children run on whatever
+# backend JAX finds (set JAX_PLATFORMS=cpu to keep them on the CPU)
+ENV = {**os.environ, "PYTHONPATH": "src"}
 
 if __name__ == "__main__":
     rc = subprocess.call(BASE, env=ENV)

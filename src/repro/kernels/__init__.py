@@ -2,24 +2,12 @@
 
 Each kernel package has:
   kernel.py — pl.pallas_call + explicit BlockSpec VMEM tiling (TPU target)
-  ops.py    — jit'd public wrapper (layout adaptation, interpret fallback)
+  ops.py    — jit'd public wrapper (layout adaptation)
   ref.py    — pure-jnp oracle used by tests/benchmarks
 
-On this CPU container kernels run with interpret=True; on a TPU backend the
-same pallas_call lowers through Mosaic.
+Every op lowers through Mosaic unless the caller passes interpret=True:
+a Pallas call on a host without a TPU fails instead of silently running
+the interpreter. Tests pass interpret=True explicitly; tests/
+test_tpu_compile.py compiles the serving-path kernels for a described
+v5e chip at published widths.
 """
-
-
-def default_interpret() -> bool:
-    import jax
-
-    return jax.default_backend() != "tpu"
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-compat constructor: pltpu.CompilerParams on current JAX,
-    pltpu.TPUCompilerParams on jax<=0.4.x (the name was changed upstream)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -1,7 +1,8 @@
 """jit'd public wrapper around the flash attention kernel.
 
-Accepts the model's [B,S,H,hd] layout, handles GQA head mapping, picks
-hardware-aligned block sizes, and falls back to interpret mode off-TPU.
+Accepts the model's [B,S,H,hd] layout, handles GQA head mapping, and
+picks hardware-aligned block sizes. Runs the Pallas interpreter only
+when the caller passes interpret=True.
 """
 from __future__ import annotations
 
@@ -9,7 +10,6 @@ from functools import partial
 
 import jax
 
-from repro.kernels import default_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 
@@ -17,10 +17,8 @@ from repro.kernels.flash_attention.kernel import flash_attention_kernel
                                    "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     n_kv_heads: int | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
+                    block_k: int = 128, interpret: bool = False):
     """q: [B,Sq,Hq,hd], k/v: [B,Sk,Hkv,hd] -> [B,Sq,Hq*hd] (model layout)."""
-    if interpret is None:
-        interpret = default_interpret()
     B, Sq, Hq, hd = q.shape
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

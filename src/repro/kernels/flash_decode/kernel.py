@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -108,7 +107,7 @@ def flash_decode_kernel(q, k, v, cur_len, *, block_k: int = 512,
             jax.ShapeDtypeStruct((B, Hq, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Hq, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

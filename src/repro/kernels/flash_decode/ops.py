@@ -8,31 +8,26 @@ import jax.numpy as jnp
 from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels import default_interpret
 from repro.kernels.flash_decode.kernel import flash_decode_kernel, merge_partials
 
 
 @partial(jax.jit, static_argnames=("block_k", "interpret"))
 def flash_decode(q, k, v, cur_len, *, block_k: int = 512,
-                 interpret: bool | None = None):
+                 interpret: bool = False):
     """Unsharded decode attention. q [B,Hq,hd]; k,v [B,Hkv,S,hd]."""
-    if interpret is None:
-        interpret = default_interpret()
     o, _, _ = flash_decode_kernel(q, k, v, cur_len, block_k=block_k,
                                   interpret=interpret)
     return o.astype(q.dtype)
 
 
 def flash_decode_seq_sharded(mesh, tp_axis: str, q, k, v, cur_len, *,
-                             block_k: int = 512, interpret: bool | None = None):
+                             block_k: int = 512, interpret: bool = False):
     """Flash-decoding over a cache whose seq dim is sharded over `tp_axis`.
 
     Each shard runs the kernel on its slice; partials merge with LSE weights
     (collective = one pmax + two psums of [B,Hq,hd] — tiny vs the cache read,
     which is the point of the layout).
     """
-    if interpret is None:
-        interpret = default_interpret()
     S = k.shape[2]
     tp = mesh.shape[tp_axis]
     s_local = S // tp

@@ -17,7 +17,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import default_interpret
 from repro.kernels.paged_decode.kernel import (
     paged_flash_decode_kernel,
     paged_flash_decode_quant_kernel,
@@ -53,13 +52,11 @@ def quantize_kv(x, axis=-1, eps=1e-8):
 
 @partial(jax.jit, static_argnames=("interpret",))
 def paged_flash_decode(q, k_pool, v_pool, tables, lengths, *,
-                       interpret: bool | None = None):
+                       interpret: bool = False):
     """Paged decode attention via the Pallas kernel.
 
     q [B,Hq,hd]; k_pool/v_pool [NB,Hkv,bs,hd]; tables [B,MB]; lengths [B].
     Returns [B,Hq,hd] f32 (callers cast)."""
-    if interpret is None:
-        interpret = default_interpret()
     return paged_flash_decode_kernel(q, k_pool, v_pool, tables, lengths,
                                      interpret=interpret)
 
@@ -86,13 +83,11 @@ def paged_gather_decode(q, k_pool, v_pool, tables, lengths):
 
 @partial(jax.jit, static_argnames=("interpret",))
 def paged_flash_decode_quant(q, k_pool, v_pool, k_scale, v_scale, tables,
-                             lengths, *, interpret: bool | None = None):
+                             lengths, *, interpret: bool = False):
     """Quantized paged decode attention via the Pallas kernel.
 
     q [B,Hq,hd]; k_pool/v_pool [NB,Hkv,bs,hd] int8; k_scale/v_scale
     [NB,Hkv,bs] f32; tables [B,MB]; lengths [B]. Returns [B,Hq,hd] f32."""
-    if interpret is None:
-        interpret = default_interpret()
     return paged_flash_decode_quant_kernel(q, k_pool, v_pool, k_scale,
                                            v_scale, tables, lengths,
                                            interpret=interpret)

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry_sc, *, bt: int):
     t = pl.program_id(2)
@@ -61,7 +59,7 @@ def rglru_scan_kernel(a, b, h0, *, block_t: int = 64, block_w: int = 512,
         out_specs=pl.BlockSpec((1, bt, wt), lambda b_, w_, t_: (b_, t_, w_)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, wt), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

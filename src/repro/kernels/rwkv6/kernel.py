@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, sfin_ref, s_sc,
                 *, ct: int, n_chunks: int):
@@ -91,7 +89,7 @@ def wkv6_kernel(r, k, v, logw, u, *, chunk: int = 64, interpret: bool = False):
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

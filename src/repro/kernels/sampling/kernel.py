@@ -17,6 +17,11 @@ below the true cutoff.
 
 Grid: (T,) — one program per batch row; per-row k and top_p ride in via
 scalar prefetch (SMEM), like the block tables in kernels/paged_decode.
+The row is viewed lane-dense as [Vp/128, 128]: its block spans the last
+two dims whole, which Mosaic accepts at any vocab (a (1, Vp) row slice is
+not a legal tile), and a 152k-entry f32 row is ~0.6 MB of VMEM. Every
+threshold is carried as a (1, 1) vector and every select has a vector
+condition — Mosaic does not lower a select on a scalar condition.
 """
 from __future__ import annotations
 
@@ -27,49 +32,53 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 NEG_INF = -1e30
 ITERS = 30  # f32 bisection: range/2^30 of slack at the boundary
+LANES = 128
 
 
 def _mask_kernel(k_ref, p_ref, x_ref, o_ref, *, V: int):
     t = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)  # [1, Vp]
-    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    x = x_ref[0].astype(jnp.float32)  # [R, 128]: the row, lane-dense
+    col = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * LANES
+           + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
     valid = col < V
     x = jnp.where(valid, x, NEG_INF)
-    k = k_ref[t]
-    top_p = p_ref[t]
+    k = jnp.full((1, 1), k_ref[t], jnp.int32)
+    top_p = jnp.full((1, 1), p_ref[t], jnp.float32)
 
-    xmax = jnp.max(x)
-    xmin = jnp.min(jnp.where(valid, x, xmax))
+    xmax = jnp.max(x, keepdims=True)  # (1, 1)
+    xmin = jnp.min(jnp.where(valid, x, xmax), keepdims=True)
 
     def k_body(_, lo_hi):
         lo, hi = lo_hi
         mid = 0.5 * (lo + hi)
-        cnt = jnp.sum(jnp.where(valid & (x >= mid), 1, 0))
+        cnt = jnp.sum(jnp.where(valid & (x >= mid), 1, 0), keepdims=True)
         ok = cnt >= k
         return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
 
     k_thr, _ = jax.lax.fori_loop(0, ITERS, k_body, (xmin, xmax + 1.0))
-    keep = jnp.where(k > 0, x >= k_thr, True)
+    # top_k <= 0 disables the filter: a threshold no entry falls below
+    k_thr = jnp.where(k > 0, k_thr, NEG_INF)
 
     e = jnp.where(valid, jnp.exp(x - xmax), 0.0)
-    probs = e / jnp.sum(e)
+    probs = e / jnp.sum(e, keepdims=True)
 
     def p_body(_, lo_hi):
         lo, hi = lo_hi
         mid = 0.5 * (lo + hi)
-        mass = jnp.sum(jnp.where(probs >= mid, probs, 0.0))
+        mass = jnp.sum(jnp.where(probs >= mid, probs, 0.0), keepdims=True)
         ok = mass >= top_p
         return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid)
 
-    p_thr, _ = jax.lax.fori_loop(0, ITERS, p_body,
-                                 (jnp.float32(0.0), jnp.float32(1.0)))
-    keep = keep & jnp.where(top_p < 1.0, probs >= p_thr, True)
+    p_thr, _ = jax.lax.fori_loop(
+        0, ITERS, p_body,
+        (jnp.zeros((1, 1), jnp.float32), jnp.ones((1, 1), jnp.float32)))
+    # top_p >= 1 disables the filter (probs are never negative)
+    p_thr = jnp.where(top_p < 1.0, p_thr, -1.0)
 
-    o_ref[...] = jnp.where(keep, x, NEG_INF).astype(o_ref.dtype)
+    keep = (x >= k_thr) & (probs >= p_thr)
+    o_ref[0] = jnp.where(keep, x, NEG_INF).astype(o_ref.dtype)
 
 
 def topk_topp_mask_kernel(logits, top_k, top_p, *, interpret: bool = False):
@@ -79,24 +88,27 @@ def topk_topp_mask_kernel(logits, top_k, top_p, *, interpret: bool = False):
     lane width internally; padded columns never survive the mask.
     """
     T, V = logits.shape
-    Vp = -(-V // 128) * 128
+    Vp = -(-V // LANES) * LANES
     if Vp != V:
         pad = jnp.full((T, Vp - V), NEG_INF, logits.dtype)
         logits = jnp.concatenate([logits, pad], axis=1)
+    R = Vp // LANES
 
     kern = functools.partial(_mask_kernel, V=V)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # per-row k and top_p land in SMEM
         grid=(T,),
-        in_specs=[pl.BlockSpec((1, Vp), lambda t, k, p: (t, 0))],
-        out_specs=pl.BlockSpec((1, Vp), lambda t, k, p: (t, 0)),
+        in_specs=[pl.BlockSpec((1, R, LANES), lambda t, k, p: (t, 0, 0))],
+        out_specs=pl.BlockSpec((1, R, LANES), lambda t, k, p: (t, 0, 0)),
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, Vp), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((T, R, LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(jnp.asarray(top_k, jnp.int32), jnp.asarray(top_p, jnp.float32), logits)
+    )(jnp.asarray(top_k, jnp.int32), jnp.asarray(top_p, jnp.float32),
+      logits.reshape(T, R, LANES))
+    out = out.reshape(T, Vp)
     return out[:, :V]

@@ -18,10 +18,7 @@ from repro.kernels.sampling.ref import topk_topp_mask_ref
 
 
 def _default_impl() -> str:
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    except Exception:  # pragma: no cover - backend probe failure
-        return "xla"
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 @partial(jax.jit, static_argnames=("impl",))

@@ -30,6 +30,7 @@ from repro.configs import get_config, get_smoke
 from repro.core import ClusterImage, LatencyPolicy, QueueDepthPolicy, \
     VirtualCluster
 from repro.core.clock import ManualClock
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as Mo
 from repro.models.env import Env
 from repro.optim.adamw import AdamWConfig
@@ -197,6 +198,7 @@ def run(args, cfg, params) -> int:
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="paper-demo")
     ap.add_argument("--smoke", action="store_true")

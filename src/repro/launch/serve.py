@@ -28,6 +28,7 @@ from repro.core import ClusterImage, LatencyPolicy, QueueDepthPolicy, \
     VirtualCluster
 from repro.core.clock import ManualClock
 from repro.launch import steps as St
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as Mo
 from repro.models.env import Env
 from repro.serve import (SERVE_PLAN, SamplingParams, burst_trace,
@@ -332,6 +333,7 @@ def run_oneshot(args, cfg, params) -> int:
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="paper-demo")
     ap.add_argument("--smoke", action="store_true")

@@ -17,9 +17,11 @@ from repro.configs import SHAPES, get_config, get_smoke
 from repro.configs.base import ParallelPlan, ShapeConfig
 from repro.core import ClusterImage, VirtualCluster
 from repro.core.elastic import ElasticTrainer
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-demo")
     ap.add_argument("--smoke", action="store_true",

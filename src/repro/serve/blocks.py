@@ -1081,8 +1081,9 @@ class QuantBlockManager(BlockManager):
     (prefill caches expand through Mo.quantize_paged_request inside the
     insert jit; the fused decode step quantizes each new token's K/V row
     in models/model.py, dispatching on the "k_scale" cache leaf), and
-    dequant fused into the read path (Pallas kernel with scalar-prefetched
-    scales on TPU, gather+multiply XLA fallback on CPU).
+    dequant fused into the read path (Pallas kernel with the scales in
+    VMEM blocks beside their int8 K/V blocks on TPU, gather+multiply XLA
+    path on CPU).
 
     At ~(hd+4)/(2*hd) the bytes per token of the bf16 pool, an equal-byte
     budget holds ~2x the blocks — ~2x admitted concurrency — with

@@ -74,12 +74,11 @@ Pytree = Any
 
 
 def _default_attn_impl() -> str:
-    """Pallas paged flash-decode on TPU; vectorized XLA gather elsewhere
-    (same math — the greedy equivalence tests hold on every backend)."""
-    try:
-        return "pallas" if jax.default_backend() == "tpu" else "naive"
-    except Exception:  # pragma: no cover - backend probe failure
-        return "naive"
+    """Pallas paged flash-decode on TPU; vectorized XLA gather elsewhere.
+    Same math, different rounding: the kernel keeps scores and
+    probabilities in f32 where the gather path rounds them to bf16, so a
+    greedy token can differ between the two at a bf16 near-tie."""
+    return "pallas" if jax.default_backend() == "tpu" else "naive"
 
 
 SERVE_PLAN = ParallelPlan(fsdp=False, remat="full",

@@ -195,8 +195,8 @@ def _quantize_pool(kp, vp):
     (1, 4, 1, 128, 32, 2),  # MQA, wide blocks
 ])
 def test_paged_decode_quant_kernel_sweep(B, Hq, Hkv, hd, bs, MB, rng):
-    """The quant kernel (fused in-register dequant, scales via scalar
-    prefetch) must match the dequantize-everything reference exactly-ish,
+    """The quant kernel (dequant folded into scores and probabilities,
+    scales in VMEM blocks) must match the dequantize-everything reference exactly-ish,
     and the whole int8 scheme must stay within the divergence bound of
     the fp pool it quantized."""
     q, kp, vp, tables = _paged_setup(rng, B, Hq, Hkv, hd, bs, MB,
@@ -406,3 +406,25 @@ def test_sampling_mask_kernel_tie_values(rng):
         keep = out[0] > NEG_INF / 2
         assert [bool(b) for b in keep[:5]] == [False, True, True, False,
                                                False], impl
+
+
+@pytest.mark.parametrize("op", ["paged", "paged_int8", "flash", "mask"])
+def test_pallas_ops_refuse_to_interpret_implicitly(op, rng):
+    """Off the TPU a Pallas op runs only when the caller asks for the
+    interpreter: the default lowers through Mosaic and fails loudly instead
+    of silently interpreting (or silently taking an XLA path)."""
+    q, kp, vp, tables = _paged_setup(rng, 2, 4, 2, 32, 8, 2, jnp.float32)
+    lengths = jnp.asarray([3, 9], jnp.int32)
+    kq, vq, ks, vs = _quantize_pool(kp, vp)
+    x = _mk(rng, (1, 128, 4, 32), jnp.float32)
+    calls = {
+        "paged": lambda: paged_flash_decode(q, kp, vp, tables, lengths),
+        "paged_int8": lambda: paged_flash_decode_quant(q, kq, vq, ks, vs,
+                                                       tables, lengths),
+        "flash": lambda: flash_attention(x, x, x),
+        "mask": lambda: topk_topp_mask(kp[0, 0], jnp.zeros((8,), jnp.int32),
+                                       jnp.ones((8,), jnp.float32),
+                                       impl="pallas"),
+    }
+    with pytest.raises(ValueError, match="interpret mode"):
+        jax.block_until_ready(calls[op]())

@@ -1,7 +1,8 @@
-"""Real multi-device behaviors (subprocess with 8 forced host devices):
+"""Real multi-device behaviors (subprocess with forced host devices):
 sharded train step parity, seq-sharded flash-decode merge, hostfile->mesh,
-mini dry-run. Kept in child processes so the main pytest session stays on
-one device (per the dry-run isolation rule)."""
+mini dry-run, elastic 2 -> 4 node scaling. Kept in child processes so the
+main pytest process stays on one device (per the dry-run isolation
+rule)."""
 import os
 import subprocess
 import sys
@@ -119,7 +120,8 @@ def test_mini_dryrun_multipod_axes():
         from repro.models.env import Env
         from repro.launch import steps as St
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_auto_mesh
+        mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_smoke("qwen3-32b")
         shape = ShapeConfig("t", 32, 8, "train")
         env = Env(mesh, ParallelPlan(fsdp=True, remat="nothing",
@@ -141,7 +143,8 @@ def test_mini_dryrun_decode_cell():
         from repro.models.env import Env
         from repro.launch import steps as St
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_auto_mesh
+        mesh = make_auto_mesh((2, 4), ("data", "model"))
         cfg = get_smoke("granite-3-8b")
         shape = ShapeConfig("t", 64, 8, "decode")
         env = Env(mesh, ParallelPlan(fsdp=False, remat="full",
@@ -153,3 +156,18 @@ def test_mini_dryrun_decode_cell():
         print("DECODE DRYRUN OK")
     """)
     assert "DECODE DRYRUN OK" in out
+
+
+def test_elastic_phase_scales_on_four_devices():
+    """chip_smoke.py --four-chip's phase at smoke size: 2 nodes train, the
+    cluster scales to 4, the state reshards onto a real 4-device mesh, and
+    every step's loss matches the same steps on one device."""
+    out = run_child(f"""
+        import sys
+        sys.path.insert(0, {ROOT!r})
+        import chip_smoke as cs
+        cs.elastic_phase(cs.ElasticConfig(arch="paper-demo", smoke=True,
+                                          seq_len=32, global_batch=8))
+        print("ELASTIC OK")
+    """, n_devices=4)
+    assert "ELASTIC OK" in out
