@@ -1,0 +1,1 @@
+from bench.lib.engine_spans import device_idle_engine_share as read  # noqa: F401

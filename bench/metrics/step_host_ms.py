@@ -1,0 +1,1 @@
+from bench.lib.engine_spans import step_host_ms as read  # noqa: F401

@@ -22,13 +22,32 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def block_computes(j, cur_len, bs: int):
+    """The kernel's liveness test: grid step j of a row whose last valid
+    position is cur_len computes (its block starts at or before it)."""
+    return j * bs <= cur_len
+
+
+def grid_programs(rows: int, lengths, bs: int,
+                  width: int) -> Tuple[int, int]:
+    """Per KV head, the programs of the grid `_paged_call` runs over
+    `rows` rows with tables `width` blocks wide, and how many of them
+    compute for the rows at depths `lengths`: block j computes where
+    block_computes(j, length, bs), so min(length // bs + 1, width) of a
+    row's blocks, none of a masked row's (-1)."""
+    live = np.clip(np.asarray(lengths) // bs + 1, 0, width)
+    return rows * width, int(live.sum())
 
 
 def _pd_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *refs, scale: float,
@@ -56,7 +75,7 @@ def _pd_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *refs, scale: float,
 
     k_start = j * bs
 
-    @pl.when(k_start <= cur_len)
+    @pl.when(block_computes(j, cur_len, bs))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # [g, hd]
         k = k_ref[0, 0].astype(jnp.float32)  # [bs, hd]

@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels.paged_decode.kernel import grid_programs
 from repro.launch import steps as St
 from repro.models import model as Mo
 from repro.models.env import Env
@@ -722,6 +723,15 @@ class BlockManager:
             params, self.caches, prev_tok, jnp.asarray(meta_i),
             jnp.asarray(meta_f), tables)
         return nxt
+
+    def kernel_grid(self, rows: int,
+                    cur_lens: np.ndarray) -> Optional[Tuple[int, int]]:
+        """The paged kernel's grid over the global tables decode() builds
+        (kernels/paged_decode/kernel.py grid_programs)."""
+        if not self.has_global:
+            return None
+        return grid_programs(rows, cur_lens, self.block_size,
+                             self.table.shape[1])
 
     # -- decode-batch views -------------------------------------------------
     def advance(self, slot: int) -> PagedSlot:

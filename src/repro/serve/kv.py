@@ -24,6 +24,7 @@ Lifecycle of one request through a backend:
     finish_prefill(slot)    chunked path: the slot joins the decode batch
                             (paged: registers full prompt blocks for reuse)
     decode(params, …)       one fused step over the whole row set
+                            (kernel_grid: how much of its grid is live)
     advance(slot)           host bookkeeping per emitted token
     finished(slot)          declared gen budget consumed?
     evict(slot)             return capacity (double-free is an error;
@@ -130,6 +131,15 @@ class KVBackend(Protocol):
         row_slots[t] names the slot whose KV row t addresses (-1: masked).
         Returns the [T] int32 device token vector; the backend swaps its
         own (donated) cache pytree."""
+        ...
+    def kernel_grid(self, rows: int,
+                    cur_lens: np.ndarray) -> Optional[Tuple[int, int]]:
+        """The attention kernel's grid for a step of `rows` rows whose
+        real rows sit at depths `cur_lens`: (programs per KV head, those
+        that compute a real row's live table block). A padding row's
+        programs count in the first and never in the second, though the
+        kernel still computes its block 0. None on a backend whose kernel
+        has no block grid."""
         ...
 
     # -- per-token bookkeeping / retirement --------------------------------

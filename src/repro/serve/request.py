@@ -35,6 +35,9 @@ class Request:
     sampling: SamplingParams = field(default_factory=SamplingParams)
     # -- filled in by the engine ------------------------------------------
     t_admit: Optional[float] = None
+    # the first step carrying one of its prompt's lane rows dispatched
+    # (classic batch-1 prefill: t_admit)
+    t_lane_start: Optional[float] = None
     t_first_token: Optional[float] = None
     t_done: Optional[float] = None
     tokens: List[int] = field(default_factory=list)

@@ -54,6 +54,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Protocol, Sequence, runtime_checkable
 
 from repro.core.clock import Clock, ManualClock
+from repro.core.spans import compile_seconds, default_recorder
 from repro.serve.metrics import percentile
 from repro.serve.policy import FIFOPolicy, SchedulerPolicy
 from repro.serve.request import Request, RequestQueue
@@ -191,6 +192,7 @@ class ReplicaSet:
         self.cfg = cfg
         self.params = params
         self.clock = clock or ManualClock()
+        self.spans = default_recorder()
         self.queue = RequestQueue()
         self.policy: SchedulerPolicy = policy or FIFOPolicy()
         self.routing: RoutingPolicy = (make_routing_policy(routing)
@@ -316,13 +318,20 @@ class ReplicaSet:
         """One fleet tick: route admissions out of the global queue, step
         every replica's fused decode batch (all within this tick — the
         data-parallel speedup is real, not a dt rescale), reap drained
-        replicas, return the fleet snapshot."""
-        now = self.clock.now()
-        self._admit_ready(now)
-        for r in self.replicas:
-            r.step_decode(now)
-        self._reap_drained()
-        return self.snapshot()
+        replicas, return the fleet snapshot. Spans as ServingEngine.step:
+        one serve.decode per replica that ran a fused step."""
+        spans, clock = self.spans, self.clock
+        with spans.span("serve.step", clock) as st:
+            c0 = compile_seconds()
+            with spans.span("serve.admit", clock):
+                self._admit_ready(st.start)
+            for r in self.replicas:
+                r.step_decode(st.start)
+            self._reap_drained()
+            with spans.span("serve.snapshot", clock):
+                snap = self.snapshot()
+            st.attrs = {"compile_s": compile_seconds() - c0}
+        return snap
 
     def _admit_ready(self, now: float) -> None:
         """The router admission loop: SchedulerPolicy picks WHO admits

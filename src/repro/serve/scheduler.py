@@ -40,6 +40,10 @@ One scheduler iteration (ServingEngine.step()):
   3. retire: finished slots (gen budget spent, or a stop token emitted)
      return their capacity to the backend.
 
+Each iteration is recorded as spans (core/spans.py) on the engine's
+clock: serve.step over serve.admit, serve.decode (serve.pack,
+serve.dispatch, serve.device_wait, serve.retire) and serve.snapshot.
+
 The engine never re-jits per admission; step shapes are pinned to
 (num_slots,) and (num_slots + prefill_chunk,) rows. Greedy decoding keeps
 output token-for-token equal to the one-shot serve_batch baseline on every
@@ -63,6 +67,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, ParallelPlan
 from repro.core.clock import Clock, ManualClock
+from repro.core.spans import compile_seconds, default_recorder
 from repro.launch import steps as St
 from repro.models.env import Env
 from repro.serve.kv import KVBackend, make_kv_backend, shared_jit
@@ -101,6 +106,19 @@ class _Lane:
     last_row: int = 0  # row of the chunk's final token (first-token source)
 
 
+@dataclass
+class _Batch:
+    """One packed step: the meta rows the fused step reads, the drafts
+    its verify rows carry, and its row and grid counts."""
+    meta_i: np.ndarray
+    meta_f: np.ndarray
+    row_slots: np.ndarray
+    sample: bool
+    drafts: Dict[int, List[int]]
+    spec_base: int
+    counts: Dict[str, int]
+
+
 class ReplicaEngine:
     """One serving replica: KVBackend + lanes + fused step + metrics.
 
@@ -129,6 +147,7 @@ class ReplicaEngine:
         self.prompt_len = prompt_len
         self.max_gen = max_gen
         self.clock = clock or ManualClock()
+        self.spans = default_recorder()
         env = Env(mesh=mesh, plan=plan or SERVE_PLAN)
         self.env = env
         if isinstance(kv, str):
@@ -269,6 +288,9 @@ class ReplicaEngine:
             return
         if self.drafter is not None:
             self.drafter.admit(req)
+        # stamped by the first step that carries one of its lane rows;
+        # a classic admission prefills here and now
+        req.t_lane_start = None if self.prefill_chunk else now
         if self.prefill_chunk:
             slot = self.pool.admit(req.rid, req.eff_gen_len,
                                    prefilling=True, prompt=req.prompt)
@@ -280,13 +302,14 @@ class ReplicaEngine:
                 pos=self.pool.cached_prefix_len(slot)))
         else:
             self._admit_classic(
-                self.pool.admit(req.rid, req.eff_gen_len), req, now)
+                self.pool.admit(req.rid, req.eff_gen_len), req)
 
-    def _admit_classic(self, slot: int, req: Request, now: float) -> None:
+    def _admit_classic(self, slot: int, req: Request) -> None:
         """Batch-1 prefill + cache insert (the non-chunked path). The first
         token is sampled from the prefill logits at position 0 — greedy
         requests take the plain argmax, bit-identical to the pre-v2 engine
-        — and fed to the same step's decode via the fresh-token path."""
+        — and fed to the same step's decode via the fresh-token path. It
+        is stamped when its transfer to the host ends."""
         logits, caches = self._prefill(
             self.params, {"tokens": jnp.asarray(req.prompt)[None]})
         self.metrics.record_prefill_tokens(len(req.prompt),
@@ -300,13 +323,14 @@ class ReplicaEngine:
             mi[St.ROW_CUR_LEN, 0] = len(req.prompt) - 1  # -> position 0
             self._fill_sampling(mi, mf, 0, req)
             first = int(self._sample_first(logits, mi, mf)[0])
-        req.t_first_token = now
+        t = self.clock.now()  # the first token's transfer has ended
+        req.t_first_token = t
         req.tokens.append(first)
         self._fresh[slot] = first
-        self.metrics.record_first_token(req, now)
-        self.metrics.record_tokens(now, 1)
+        self.metrics.record_first_token(req, t)
+        self.metrics.record_tokens(t, 1)
         if self.pool.finished(slot) or first in req.sampling.stop_set:
-            self._retire(slot, now)  # gen_len == 1 / instant stop token
+            self._retire(slot, t)  # gen_len == 1 / instant stop token
 
     # -- preemption (restart-style) ------------------------------------------
     def running(self) -> List[Request]:
@@ -364,6 +388,7 @@ class ReplicaEngine:
         victim.t_admit = None
         if not swapped:
             victim.tokens.clear()
+            victim.t_lane_start = None
             victim.t_first_token = None
             victim.restarts += 1
         self.metrics.record_preempt(now)
@@ -412,12 +437,41 @@ class ReplicaEngine:
     def step_decode(self, now: float) -> int:
         """Run one fused decode step over the replica's mixed batch
         (+ prefill lanes) and retire finished requests. Returns tokens
-        emitted this tick."""
+        emitted this tick.
+
+        Recorded as a `serve.decode` span whose children split the tick:
+        serve.pack (rows, meta, block growth), serve.dispatch (the
+        backend's table build, uploads and jitted call), serve.device_wait
+        (the one transfer) and serve.retire; the step's row and grid
+        counts are its attributes. Tokens are stamped when the transfer
+        that brought them ends, not at `now` (the iteration's start)."""
         active = self.pool.active_slots()
         lanes = self._lanes
         if not active and not lanes:
             return 0
+        spans, clock = self.spans, self.clock
+        with spans.span("serve.decode", clock) as dec:
+            with spans.span("serve.pack", clock):
+                b = self._pack(active, lanes)
+            dec.attrs = b.counts
+            with spans.span("serve.dispatch", clock) as disp:
+                for lane in lanes:
+                    if lane.take > 0 and lane.req.t_lane_start is None:
+                        lane.req.t_lane_start = disp.start
+                nxt_dev = self.pool.decode(self.params, self._tok_prev,
+                                           b.meta_i, b.meta_f, b.row_slots,
+                                           sample=b.sample)
+                self._tok_prev = nxt_dev
+            with spans.span("serve.device_wait", clock) as wait:
+                nxt = np.asarray(nxt_dev)  # the one host transfer per step
+            self.decode_steps += 1
+            with spans.span("serve.retire", clock):
+                return self._emit(active, lanes, b, nxt, wait.end)
 
+    def _pack(self, active: List[int], lanes: List[_Lane]) -> "_Batch":
+        """Pack one step's rows: decode rows at their slots, then the
+        prefill lanes' token budget, then the verify rows; grow each
+        slot's KV to cover the positions its rows write."""
         # pack the prefill token budget FIFO across open lanes
         N = self.pool.num_slots
         budget = self.prefill_chunk
@@ -504,13 +558,21 @@ class ReplicaEngine:
             sample |= self._fill_sampling(meta_i, meta_f, sl, lane.req)
             row += lane.take
             lane.last_row = row - 1
+        # real rows: decode rows, lane rows with a take, verify rows that
+        # carry a draft; every other row is padding the step still runs
+        depths = meta_i[St.ROW_CUR_LEN][row_slots >= 0]
+        counts = {"rows": T, "real_rows": len(depths)}
+        grid = self.pool.kernel_grid(T, depths)
+        if grid is not None:
+            counts["grid_cells"], counts["grid_live"] = grid
+        return _Batch(meta_i, meta_f, row_slots, sample, drafts, spec_base,
+                      counts)
 
-        nxt_dev = self.pool.decode(self.params, self._tok_prev, meta_i,
-                                   meta_f, row_slots, sample=sample)
-        self._tok_prev = nxt_dev
-        nxt = np.asarray(nxt_dev)  # the one host transfer per step
-        self.decode_steps += 1
-
+    def _emit(self, active: List[int], lanes: List[_Lane], b: "_Batch",
+              nxt: np.ndarray, t: float) -> int:
+        """Hand the step's tokens to their requests (stamped at t), close
+        the lanes whose prompt is done, retire finished requests."""
+        drafts, spec_base = b.drafts, b.spec_base
         emitted = 0
         for slot in active:
             info = self.pool.info(slot)
@@ -530,7 +592,7 @@ class ReplicaEngine:
                 a += 1
             emit = outs[:a + 1]
             stop = req.sampling.stop_set
-            cut = next((i for i, t in enumerate(emit) if t in stop), None)
+            cut = next((i for i, x in enumerate(emit) if x in stop), None)
             if cut is not None:
                 emit = emit[:cut + 1]
             if d:
@@ -549,7 +611,7 @@ class ReplicaEngine:
                 req.tokens.append(tok)
                 emitted += 1
             if self.pool.finished(slot) or emit[-1] in stop:
-                self._retire(slot, now)
+                self._retire(slot, t)
         still_open: List[_Lane] = []
         for lane in lanes:
             lane.pos += lane.take
@@ -559,18 +621,18 @@ class ReplicaEngine:
             slot = lane.slot
             self.pool.finish_prefill(slot)
             req = lane.req
-            req.t_first_token = now
+            req.t_first_token = t
             tok = int(nxt[lane.last_row])
             req.tokens.append(tok)
-            self.metrics.record_first_token(req, now)
+            self.metrics.record_first_token(req, t)
             # next step, this slot's input token comes from the lane row
             self._row_src[slot] = lane.last_row
             emitted += 1
             if self.pool.finished(slot) or tok in req.sampling.stop_set:
-                self._retire(slot, now)
+                self._retire(slot, t)
         self._lanes = still_open
         if emitted:
-            self.metrics.record_tokens(now, emitted)
+            self.metrics.record_tokens(t, emitted)
         return emitted
 
     @staticmethod
@@ -780,11 +842,20 @@ class ServingEngine:
     def step(self) -> Dict[str, float]:
         """Admit arrivals (policy order), run one fused decode step over
         the mixed batch (+ prefill lanes), retire finished requests.
-        Returns the metrics snapshot (what a node would publish)."""
-        now = self.clock.now()
-        self._admit_ready(now)
-        self.replica.step_decode(now)
-        return self.snapshot()
+        Returns the metrics snapshot (what a node would publish).
+        Recorded as a `serve.step` span (attribute compile_s: seconds JAX
+        spent tracing and compiling inside it) over serve.admit,
+        serve.decode and serve.snapshot."""
+        spans, clock = self.replica.spans, self.clock
+        with spans.span("serve.step", clock) as st:
+            c0 = compile_seconds()
+            with spans.span("serve.admit", clock):
+                self._admit_ready(st.start)
+            self.replica.step_decode(st.start)
+            with spans.span("serve.snapshot", clock):
+                snap = self.snapshot()
+            st.attrs = {"compile_s": compile_seconds() - c0}
+        return snap
 
     # -- admission ----------------------------------------------------------------
     def _admit_ready(self, now: float) -> None:

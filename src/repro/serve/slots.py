@@ -22,9 +22,10 @@ from __future__ import annotations
 
 from collections import deque
 from dataclasses import dataclass
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.launch import steps as St
@@ -254,6 +255,10 @@ class SlotPool:
             params, self.caches, prev_tok, jnp.asarray(meta_i),
             jnp.asarray(meta_f))
         return nxt
+
+    def kernel_grid(self, rows: int,
+                    cur_lens: np.ndarray) -> Optional[Tuple[int, int]]:
+        return None  # contiguous rows: the kernel has no block grid
 
     # -- decode-batch views ---------------------------------------------------
     def advance(self, slot: int) -> SlotInfo:
