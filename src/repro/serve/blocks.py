@@ -726,8 +726,8 @@ class BlockManager:
 
     def kernel_grid(self, rows: int,
                     cur_lens: np.ndarray) -> Optional[Tuple[int, int]]:
-        """The paged kernel's grid over the global tables decode() builds
-        (kernels/paged_decode/kernel.py grid_programs)."""
+        """The paged kernel's chunk loop over the global tables decode()
+        builds (kernels/paged_decode/kernel.py grid_programs)."""
         if not self.has_global:
             return None
         return grid_programs(rows, cur_lens, self.block_size,

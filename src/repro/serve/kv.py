@@ -134,12 +134,12 @@ class KVBackend(Protocol):
         ...
     def kernel_grid(self, rows: int,
                     cur_lens: np.ndarray) -> Optional[Tuple[int, int]]:
-        """The attention kernel's grid for a step of `rows` rows whose
-        real rows sit at depths `cur_lens`: (programs per KV head, those
-        that compute a real row's live table block). A padding row's
-        programs count in the first and never in the second, though the
-        kernel still computes its block 0. None on a backend whose kernel
-        has no block grid."""
+        """The attention kernel's work for a step of `rows` rows whose
+        real rows sit at depths `cur_lens`: (block slots its chunk loop
+        visits per KV head, those that hold a real row's live table
+        block). A padding row's chunk counts in the first and never in
+        the second, though the kernel still computes its block 0. None
+        on a backend whose kernel has no block table."""
         ...
 
     # -- per-token bookkeeping / retirement --------------------------------

@@ -13,6 +13,7 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.flash_decode.ops import flash_decode
 from repro.kernels.flash_decode.ref import decode_ref
+from repro.kernels.paged_decode.kernel import chunk_blocks
 from repro.kernels.paged_decode.ops import (paged_flash_decode,
                                             paged_flash_decode_quant,
                                             paged_gather_decode,
@@ -228,6 +229,64 @@ def test_paged_decode_quant_masks_fully_and_partially(rng):
                                  interpret=True)
     r = paged_decode_quant_ref(q, kq, vq, ks, vs, tables, lengths)
     assert float(jnp.max(jnp.abs(o[0]))) == 0.0, "masked row must be zero"
+    assert float(jnp.max(jnp.abs(o - r))) < 2e-3
+
+
+def _paged_run(quant, q, kp, vp, tables, lengths):
+    """The bf16 or int8 (pool quantized here) kernel beside its oracle."""
+    if not quant:
+        return (paged_flash_decode(q, kp, vp, tables, lengths, interpret=True),
+                paged_decode_ref(q, kp, vp, tables, lengths))
+    kq, vq, ks, vs = _quantize_pool(kp, vp)
+    return (paged_flash_decode_quant(q, kq, vq, ks, vs, tables, lengths,
+                                     interpret=True),
+            paged_decode_quant_ref(q, kq, vq, ks, vs, tables, lengths))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("Hq,Hkv,hd,bs,MB", [
+    (12, 2, 128, 16, 12),  # g 6 (qwen2-1.5b widths); 12 = 8 + a short chunk
+    (16, 2, 64, 16, 10),   # g 8; a width that is not a multiple of C = 8
+    (8, 2, 32, 8, 20),     # block 8: C = 16 over a 20-block table
+])
+def test_paged_decode_lengths_at_chunk_edges(Hq, Hkv, hd, bs, MB, quant,
+                                             rng):
+    """Rows at -1, 0, the last position of the first chunk, the first of
+    the second, and the table's last position: the chunk loop runs 0, 1,
+    1, 2 and every chunk, and each row matches the oracle."""
+    C = chunk_blocks(bs, MB)
+    lengths = jnp.asarray([-1, 0, C * bs - 1, C * bs, MB * bs - 1], jnp.int32)
+    q, kp, vp, tables = _paged_setup(rng, 5, Hq, Hkv, hd, bs, MB,
+                                     jnp.bfloat16)
+    o, r = _paged_run(quant, q, kp, vp, tables, lengths)
+    assert float(jnp.max(jnp.abs(o[0]))) == 0.0, "masked row must be zero"
+    assert float(jnp.max(jnp.abs(o - r))) < 2e-3
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_decode_never_reads_dead_blocks(quant, rng):
+    """Every table entry past a row's live blocks names a block filled
+    with NaN (int8: its scales): the output is finite and matches the
+    oracle over the real tables, so no dead block reaches the math."""
+    B, Hq, Hkv, hd, bs, MB = 5, 12, 2, 128, 16, 12
+    q, kp, vp, tables = _paged_setup(rng, B, Hq, Hkv, hd, bs, MB,
+                                     jnp.float32, extra_blocks=1)
+    nan_blk, = set(range(1, kp.shape[0])) - set(np.asarray(tables).flat)
+    lengths = jnp.asarray([0, 8 * bs - 1, 8 * bs, 3 * bs + 5, MB * bs - 1],
+                          jnp.int32)
+    dead = jnp.arange(MB)[None, :] > lengths[:, None] // bs
+    poisoned = jnp.where(dead, nan_blk, tables)
+    if not quant:
+        kp, vp = kp.at[nan_blk].set(jnp.nan), vp.at[nan_blk].set(jnp.nan)
+        o = paged_flash_decode(q, kp, vp, poisoned, lengths, interpret=True)
+        r = paged_decode_ref(q, kp, vp, tables, lengths)
+    else:
+        kq, vq, ks, vs = _quantize_pool(kp, vp)
+        ks, vs = ks.at[nan_blk].set(jnp.nan), vs.at[nan_blk].set(jnp.nan)
+        o = paged_flash_decode_quant(q, kq, vq, ks, vs, poisoned, lengths,
+                                     interpret=True)
+        r = paged_decode_quant_ref(q, kq, vq, ks, vs, tables, lengths)
+    assert bool(jnp.all(jnp.isfinite(o)))
     assert float(jnp.max(jnp.abs(o - r))) < 2e-3
 
 

@@ -19,7 +19,8 @@ from repro.configs import get_smoke
 from repro.core import spans as S
 from repro.core.clock import Clock, ManualClock
 from repro.core.spans import SpanRecorder
-from repro.kernels.paged_decode.kernel import block_computes, grid_programs
+from repro.kernels.paged_decode.kernel import (block_computes, chunk_blocks,
+                                              grid_programs, live_blocks)
 from repro.models import model as Mo
 from repro.models.env import Env
 from repro.serve import (SERVE_PLAN, Request, ServingEngine,
@@ -187,9 +188,11 @@ def test_replica_set_records_the_same_tree(rec):
 
 def test_row_and_grid_counters_of_a_batch_built_by_hand(rec):
     """2 slots, a 24-row lane budget, block 16, a 32 + 8 budget: the
-    table is 3 blocks wide. Step 1 runs request 0's 20-token prompt
-    (depths 0..19: 16 rows in block 0, 4 reach block 1) in 2 + 24 rows;
-    step 2 decodes it at depth 20 beside request 1's 12-token lane."""
+    table is 3 blocks wide, so the paged kernel's chunk is the whole table
+    (C = 3) and every row, padding included, runs one chunk of 3 slots.
+    Step 1 runs request 0's 20-token prompt (depths 0..19: 16 rows in
+    block 0, 4 reach block 1) in 2 + 24 rows; step 2 decodes it at depth
+    20 beside request 1's 12-token lane."""
     eng = _engine()
     eng.submit([_req(0, 20, gen_len=4)])
     eng.step()
@@ -207,15 +210,24 @@ def test_row_and_grid_counters_of_a_batch_built_by_hand(rec):
                   "grid_live": 2 + 1}
 
 
-@pytest.mark.parametrize("bs,width", [(16, 3), (8, 5), (1, 4)])
+@pytest.mark.parametrize("bs,width", [(16, 3), (8, 5), (1, 4), (16, 12),
+                                      (16, 192), (8, 20)])
 def test_the_grid_count_is_the_kernels_liveness_test(bs, width):
-    """grid_programs counts, per row, the grid steps j for which the
-    paged kernel's block_computes holds (a masked row, -1, computes
-    none; a row past the table's end computes all of it)."""
+    """grid_programs counts, block by block, the slots of the paged
+    kernel's chunk loop: a row runs ceil(live_blocks / C) chunks of C
+    slots, and a slot holds a live block where block_computes holds and
+    the table has the block (a masked row, -1, runs none; a row past the
+    table's end reads all of it); the three rows past the six lengths are
+    padding at depth 0, one chunk each, never live."""
     lengths = np.array([-1, 0, bs - 1, bs, 2 * bs + 3, width * bs + 7])
-    j = np.arange(width)[None, :]
-    brute = int(block_computes(j, lengths[:, None], bs).sum())
-    assert grid_programs(9, lengths, bs, width) == (9 * width, brute)
+    C = chunk_blocks(bs, width)
+    cells = live = 0
+    for L, real in [(L, True) for L in lengths] + [(0, False)] * 3:
+        for c in range(-(-int(live_blocks(np.asarray(L), bs, width)) // C)):
+            for j in range(c * C, (c + 1) * C):
+                cells += 1
+                live += real and bool(block_computes(j, L, bs)) and j < width
+    assert grid_programs(9, lengths, bs, width) == (cells, live)
 
 
 def test_the_slot_backend_counts_rows_but_no_grid(rec):

@@ -58,25 +58,34 @@ def _compile(fn, *args):
     return compiled
 
 
-def _paged_args(arch, sh, dtype):
+# (arch, rows, table width, pool blocks): the small step at both widths,
+# and the benchmark's lane step: 64 slots + 512 lane rows over a
+# (2048 + 1024) / 16 = 192-block table, a pool of 12 288 blocks + null
+PAGED_SHAPES = [pytest.param(a, ROWS, MAX_BLOCKS, 1 + ROWS * MAX_BLOCKS,
+                             id=a) for a in WIDTHS] + [
+    pytest.param("qwen2-1.5b", 576, 192, 12289, id="qwen2-1.5b-576x192")]
+
+
+def _paged_args(arch, rows, width, nb, sh, dtype):
     cfg = get_config(arch)
-    nb = 1 + ROWS * MAX_BLOCKS
     pool = (nb, cfg.n_kv_heads, BLOCK_SIZE, cfg.head_dim)
-    return (cfg, _sds((ROWS, cfg.n_heads, cfg.head_dim), jnp.bfloat16, sh),
+    return (cfg, _sds((rows, cfg.n_heads, cfg.head_dim), jnp.bfloat16, sh),
             _sds(pool, dtype, sh), _sds(pool, dtype, sh),
-            _sds((ROWS, MAX_BLOCKS), jnp.int32, sh),
-            _sds((ROWS,), jnp.int32, sh))
+            _sds((rows, width), jnp.int32, sh),
+            _sds((rows,), jnp.int32, sh))
 
 
-@pytest.mark.parametrize("arch", WIDTHS)
-def test_paged_decode_compiles(arch, one_chip):
-    _, q, kp, vp, tbl, lens = _paged_args(arch, one_chip, jnp.bfloat16)
+@pytest.mark.parametrize("arch,rows,width,nb", PAGED_SHAPES)
+def test_paged_decode_compiles(arch, rows, width, nb, one_chip):
+    _, q, kp, vp, tbl, lens = _paged_args(arch, rows, width, nb, one_chip,
+                                          jnp.bfloat16)
     _compile(paged_flash_decode, q, kp, vp, tbl, lens)
 
 
-@pytest.mark.parametrize("arch", WIDTHS)
-def test_paged_decode_quant_compiles(arch, one_chip):
-    cfg, q, kp, vp, tbl, lens = _paged_args(arch, one_chip, jnp.int8)
+@pytest.mark.parametrize("arch,rows,width,nb", PAGED_SHAPES)
+def test_paged_decode_quant_compiles(arch, rows, width, nb, one_chip):
+    cfg, q, kp, vp, tbl, lens = _paged_args(arch, rows, width, nb, one_chip,
+                                            jnp.int8)
     scales = _sds(kp.shape[:3], jnp.float32, one_chip)
     _compile(paged_flash_decode_quant, q, kp, vp, scales, scales, tbl, lens)
 
