@@ -60,13 +60,13 @@ def readings(cell, seeds, control_seeds, seconds):
     hf = cell.config
     for seed in seeds:
         t0 = time.monotonic()
-        w = weights.make(hf, hf["weights"], seed)
+        w = weights.make(cell.reference.shapes(hf), hf["weights"], seed)
         jax.block_until_ready(w)
         served = harness.serve(cell, w, seed, seconds, t_start=t0)
         win = served.window
         row = {"workload": cell.name, "seed": seed,
-               **check.readings(win.recs, win.w1, w, hf, seed,
-                                control=seed in control_seeds),
+               **check.readings(cell.reference, win.recs, win.w1, w, hf,
+                                seed, control=seed in control_seeds),
                "wrong_length_requests":
                    check.wrong_length(win.recs, win.w1, hf["vocab_size"]),
                "end_to_end": result.end_to_end(win, served.setup_s),

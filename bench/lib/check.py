@@ -3,7 +3,8 @@
 Once the window has closed and the engine is gone, two samples of the
 requests that finished by the close, each drawn from the seed and always
 with the longest request of its kind, are run through the plain float32
-reference (bench/reference/), each prompt with its served tokens:
+reference of the configuration's architecture (bench/reference/<key>.py,
+passed in as `ref`), each prompt with its served tokens:
 
 - greedy requests: at every served position the reference's best logit
   minus the logit of the token served is the logit gap: 0 where the
@@ -23,9 +24,9 @@ configuration's least number of compared tokens. Every finished request
 must hold exactly its requested number of in-vocabulary tokens.
 
 The control (bench/control.py) reads the same gaps for the tokens the
-int8 reference puts in the program's place: its argmax for greedy rows,
-and for sampled rows a token drawn (Gumbel-max, seeded) from the int8
-logits' own filtered set.
+int8 reference (`logits_at(..., quant=True)`) puts in the program's place:
+its argmax for greedy rows, and for sampled rows a token drawn
+(Gumbel-max, seeded) from the int8 logits' own filtered set.
 """
 from __future__ import annotations
 
@@ -34,8 +35,6 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from bench.reference import dense_gqa
 
 KINDS = ("greedy", "sampled")
 # the statistics of each kind's gaps (statistics() names them so)
@@ -107,13 +106,13 @@ def sample_kept(q, s, rng) -> np.ndarray:
     return z.argmax(axis=-1)
 
 
-def gaps(w, hf: dict, engine: dict, sample, *, control: bool = False,
+def gaps(ref, w, hf: dict, engine: dict, sample, *, control: bool = False,
          seed: int = 0) -> Dict[str, List[List[float]]]:
     """Per request of the sample, the gap at each served token (the logit
     gap for a greedy request, the mask gap for a sampled one); with
     control, also the gap of the int8 reference's own token at the same
     positions."""
-    hp = dense_gqa.hparams_of(hf)
+    hp = ref.hparams_of(hf)
     seq_len = engine["prompt_len"] + engine["max_gen"]
     out = {"program": [], "control": []}
     V = hf["vocab_size"]
@@ -121,18 +120,18 @@ def gaps(w, hf: dict, engine: dict, sample, *, control: bool = False,
     for r in sample:
         toks, pos, served = _padded(r, seq_len, engine["max_gen"])
         n, rows = len(served), np.arange(len(served))
-        ref = np.asarray(dense_gqa.logits_at(w, toks, pos, hp))[:n, :V]
+        lg = np.asarray(ref.logits_at(w, toks, pos, hp))[:n, :V]
         s = r.spec.sampling
         if s is None:
-            edge = ref.max(axis=-1)
+            edge = lg.max(axis=-1)
         else:
-            edge = np.asarray(kept_threshold(jnp.asarray(ref), s.temperature,
+            edge = np.asarray(kept_threshold(jnp.asarray(lg), s.temperature,
                                              s.top_k, s.top_p))
-        gap = lambda tok: np.maximum(edge - ref[rows, tok], 0.0).tolist()
+        gap = lambda tok: np.maximum(edge - lg[rows, tok], 0.0).tolist()
         out["program"].append(gap(served))
         if control:
-            q = np.asarray(dense_gqa.logits_at(w, toks, pos, hp,
-                                               quant=True))[:n, :V]
+            q = np.asarray(ref.logits_at(w, toks, pos, hp,
+                                         quant=True))[:n, :V]
             out["control"].append(gap(q.argmax(axis=-1) if s is None
                                       else sample_kept(q, s, rng)))
     return out
@@ -150,7 +149,7 @@ def statistics(rows: List[List[float]], kind: str = "greedy"
             f"mean_{name}_gap": sum(flat) / len(flat)}
 
 
-def readings(recs, w1: float, w, hf: dict, seed: int, *,
+def readings(ref, recs, w1: float, w, hf: dict, seed: int, *,
              control: bool = False, kinds=KINDS) -> Dict[str, dict]:
     """For each kind of request, the sample's size and the statistics of
     its gaps for the program and, with control, for the control."""
@@ -159,7 +158,7 @@ def readings(recs, w1: float, w, hf: dict, seed: int, *,
     for kind in kinds:
         sample = pick(recs, w1, seed, c["sample_tokens"], c["max_requests"],
                       kind)
-        g = gaps(w, hf, e, sample, control=control, seed=seed)
+        g = gaps(ref, w, hf, e, sample, control=control, seed=seed)
         out[kind] = {"requests": len(sample),
                      "tokens": sum(len(x) for x in g["program"]),
                      "program": statistics(g["program"], kind),
@@ -169,13 +168,13 @@ def readings(recs, w1: float, w, hf: dict, seed: int, *,
     return out
 
 
-def judge(recs, w1: float, w, hf: dict, seed: int,
+def judge(ref, recs, w1: float, w, hf: dict, seed: int,
           limits: Dict[str, float]) -> Dict[str, dict]:
     """The numbers compared, each with its limit; all must hold. Only the
     kinds of request that `limits` names a statistic of are read."""
     c = hf["check"]
     kinds = [k for k in KINDS if any(f"_{GAP[k]}_" in n for n in limits)]
-    got = readings(recs, w1, w, hf, seed, kinds=kinds)
+    got = readings(ref, recs, w1, w, hf, seed, kinds=kinds)
     st = {n: v for k in kinds for n, v in got[k]["program"].items()}
     out = {name: {"value": st[name], "limit": lim}
            for name, lim in limits.items()}

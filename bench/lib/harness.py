@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.lib import traffic, weights, work
+from bench.lib import traffic, work
 from bench.lib import trace as T
 from repro.configs.base import ModelConfig
 from repro.core.clock import RealClock
@@ -38,23 +38,12 @@ TRACE_SLICE_S = 6.0
 WARM_GEN = 3
 
 
-def model_config(hf: dict) -> ModelConfig:
-    """The program's config for a configuration file's published keys."""
-    return ModelConfig(
-        name=hf["name"], family="dense", n_layers=hf["num_hidden_layers"],
-        d_model=hf["hidden_size"], n_heads=hf["num_attention_heads"],
-        n_kv_heads=hf["num_key_value_heads"], d_ff=hf["intermediate_size"],
-        vocab_size=hf["vocab_size"], head_dim=hf["head_dim"],
-        qkv_bias=hf["attention_bias"], rope_theta=float(hf["rope_theta"]),
-        norm_eps=hf["rms_norm_eps"], tie_embeddings=hf["tie_word_embeddings"],
-        block_pattern=("attn",), source=hf["source"])
-
-
-def program_params(mcfg: ModelConfig, hf: dict, w: dict) -> dict:
-    """The canonical weights in the program's layout, checked leaf by leaf
-    against the shapes and types the program's own init would make."""
+def program_params(arch, mcfg: ModelConfig, hf: dict, w: dict) -> dict:
+    """The canonical weights in the program's layout (the architecture
+    module's program_params), checked leaf by leaf against the shapes and
+    types the program's own init would make."""
     env = Env(None, SERVE_PLAN)
-    params = weights.program_params(w, hf, vocab_pad(mcfg, env))
+    params = arch.program_params(w, hf, vocab_pad(mcfg, env))
     want = jax.eval_shape(lambda k: Mo.init_params(k, mcfg, env),
                           jax.ShapeDtypeStruct((2,), jnp.uint32))
     got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -272,11 +261,10 @@ def _account(eng, live: List[Rec], lanes: List[list], prefilled: int,
         sw.consistent &= ok
         sw.steps += 1
         sw.rows += len(keys)
-        sw.flops += sum(work.row_flops(dims, k) for k in keys)
-        sw.pd_flops += sum(work.attn_flops(dims, k) for k in keys)
-        sw.pd_bytes += work.paged_attn_bytes(dims, seq_keys.values(),
-                                             len(keys))
-        sw.mask_bytes += work.mask_bytes(dims, sampled)
+        sw.flops += sum(dims.row_flops(k) for k in keys)
+        sw.pd_flops += sum(dims.attn_flops(k) for k in keys)
+        sw.pd_bytes += dims.paged_attn_bytes(seq_keys.values(), len(keys))
+        sw.mask_bytes += dims.mask_bytes(sampled)
 
 
 def schedule(cell, seed: int, seconds: float, t0: float) -> List[Rec]:
@@ -310,15 +298,15 @@ def serve(cell, w: dict, seed: int, seconds: float, *, t_start: float,
     w. The engine is gone when this returns: its KV pool is freed before
     the reference runs."""
     hf, e = cell.config, cell.config["engine"]
-    mcfg = model_config(hf)
-    params = program_params(mcfg, hf, w)
+    mcfg = cell.arch.model_config(hf)
+    params = program_params(cell.arch, mcfg, hf, w)
     eng = build_engine(mcfg, params, e)
     warm_up(eng, cell.mix, e, hf["vocab_size"], seed)
     t0 = eng.clock.now()
     recs = schedule(cell, seed, seconds, t0)
     eng.submit([r.req for r in recs])
     w0 = t0 + float(cell.mix["lead_in_s"])
-    win = drive(eng, recs, work.Dims.from_config(hf), w0=w0,
+    win = drive(eng, recs, cell.arch.work(hf), w0=w0,
                 w1=w0 + seconds, trace_dir=trace_dir)
     jax.block_until_ready(eng.pool.caches)
     peak = memory_peak_bytes()

@@ -18,7 +18,7 @@ class Ctx:
     window: harness.Window
     summary: Optional[T.Summary]  # the device trace; None off the TPU
     peak: Optional[dict]  # the chip's peaks; None off the TPU
-    dims: work.Dims
+    dims: work.Dims  # the architecture module's work(hf)
     config: dict
 
 
@@ -44,7 +44,7 @@ def end_to_end(win: harness.Window, setup_s: float) -> dict:
 def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         t_start: float) -> dict:
     hf = cell.config
-    w = weights.make(hf, hf["weights"], seed)
+    w = weights.make(cell.reference.shapes(hf), hf["weights"], seed)
     jax.block_until_ready(w)
     on_tpu = jax.devices()[0].platform == "tpu"
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -55,7 +55,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
     win = served.window
-    numbers = check.judge(win.recs, win.w1, w, hf, seed,
+    numbers = check.judge(cell.reference, win.recs, win.w1, w, hf, seed,
                           cell.settings["limits"])
     dev = device_info(served.memory_peak_bytes)
     out = {"correct": check.holds(numbers),
@@ -72,7 +72,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     else:
         summary = T.reduce(win.trace) if on_tpu and win.trace else None
         ctx = Ctx(win, summary, spec.peaks(dev["kind"]) if on_tpu else None,
-                  work.Dims.from_config(hf), hf)
+                  cell.arch.work(hf), hf)
         for m in cell.per_layer:
             v = spec.reader(m["name"])(ctx)
             if v is not None:

@@ -2,15 +2,21 @@
 names the cell, its configuration file and its traffic mix; the rest are
 files under the benchmark's directory, one per piece:
 
-  configs/<config>.json   sizes, cuts, weights' init, engine, check sizes
+  configs/<config>.json   sizes, cuts, weights' init, engine, check sizes;
+                          its `reference` key names the two modules below
+  reference/<key>.py      the architecture's plain float32 reference:
+                          shapes(hf), hparams_of(hf), logits_at(...)
+  arch/<key>.py           how the program takes that architecture:
+                          model_config(hf), program_params(w, hf, vocab),
+                          work(hf) (bench/lib/work.py)
   traffic/<mix>.json      the mix's parameters (bench/lib/traffic.py)
   cells/<cell>.json       the cell's fixed offered rate (its knee sweep)
                           and the limits of its correctness check
   metrics/<metric>.py     a per-layer metric's reader: read(ctx) -> number
                           or None when it finds nothing to read
 
-A later cell, mix, configuration or metric is new files and new entries in
-BENCHMARK.json; nothing here changes.
+A later cell, mix, configuration, architecture or metric is new files and
+new entries in BENCHMARK.json; nothing here changes.
 """
 from __future__ import annotations
 
@@ -18,6 +24,7 @@ import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -35,6 +42,8 @@ class Cell:
     settings: dict  # the cell file: rate_rps, limits
     end_to_end: List[dict]
     per_layer: List[dict]
+    reference: ModuleType  # bench/reference/<config's reference key>.py
+    arch: ModuleType  # bench/arch/<config's reference key>.py
 
 
 def load_json(path: Path) -> dict:
@@ -57,28 +66,41 @@ def find_cell(name: str, *, root: Path = ROOT,
                        f"{sorted(cells)}")
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = load_json(root / conf["file"])
+    ref = module("reference", config["reference"], root=root)
+    arch = module("arch", config["reference"], root=root)
     e2e = [m for m in bench["end_to_end"] if _reports(m, name)]
     e2e_names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in e2e_names)]
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
-                config=load_json(root / conf["file"]),
-                mix_name=w["traffic"],
+                config=config, mix_name=w["traffic"],
                 mix=load_json(root / "bench" / "traffic"
                               / f"{w['traffic']}.json"),
                 settings=load_json(root / "bench" / "cells" / f"{name}.json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer,
+                reference=ref, arch=arch)
+
+
+def module(kind: str, key: str, *, root: Path = ROOT) -> ModuleType:
+    """bench/<kind>/<key>.py under root, loaded by path: an architecture's
+    reference or arch module, or a per-layer metric's reader. A missing
+    file is an error that names it."""
+    path = root / "bench" / kind / f"{key}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {path.relative_to(root)} under {root} "
+                                f"for {kind} {key!r}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{key.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(metric: str, *, root: Path = ROOT) -> Callable:
     """A per-layer metric's reader, loaded from bench/metrics/<name>.py."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return module("metrics", metric, root=root).read
 
 
 def peaks(device_kind: str, *, root: Path = ROOT) -> Dict[str, float]:

@@ -5,7 +5,8 @@ residual -> RMSNorm -> SwiGLU MLP -> residual, then a final RMSNorm and
 the output head. Written from the published description, with no kernel,
 cache or batching, and importing nothing of the system under test.
 
-Weights are the benchmark's canonical dict (bench/lib/weights.py):
+Weights are the benchmark's canonical dict, as shapes() lays it out and
+bench/lib/weights.py draws it from the seed:
   embed [V, d]; lm_head [d, V] or absent when tied (then embed.T);
   final_norm [d] gain; layers: stacked over the layer axis
   ln1, ln2 [L, d] gains; wq [L, d, Hq*hd], wk/wv [L, d, Hkv*hd],
@@ -28,6 +29,30 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+
+def shapes(hf: dict) -> dict:
+    """Canonical leaf -> (shape, dtype, init kind); a dict value is a group
+    of leaves (here the layers, stacked)."""
+    L, d, ff, V = (hf["num_hidden_layers"], hf["hidden_size"],
+                   hf["intermediate_size"], hf["vocab_size"])
+    hq, hkv, hd = (hf["num_attention_heads"], hf["num_key_value_heads"],
+                   hf["head_dim"])
+    bf, f32 = jnp.bfloat16, jnp.float32
+    layers = {"ln1": ((L, d), f32, "gain"), "ln2": ((L, d), f32, "gain"),
+              "wq": ((L, d, hq * hd), bf, "w"),
+              "wk": ((L, d, hkv * hd), bf, "w"),
+              "wv": ((L, d, hkv * hd), bf, "w"),
+              "wo": ((L, hq * hd, d), bf, "w"),
+              "w_gate": ((L, d, ff), bf, "w"), "w_up": ((L, d, ff), bf, "w"),
+              "w_down": ((L, ff, d), bf, "w")}
+    if hf["attention_bias"]:
+        layers.update(bq=((L, hq * hd), bf, "b"), bk=((L, hkv * hd), bf, "b"),
+                      bv=((L, hkv * hd), bf, "b"))
+    top = {"embed": ((V, d), bf, "w"), "final_norm": ((d,), f32, "gain")}
+    if not hf["tie_word_embeddings"]:
+        top["lm_head"] = ((d, V), bf, "w")
+    return {"layers": layers, **top}
 
 
 def rms_norm(x, gain, eps):

@@ -77,7 +77,7 @@ def sweep(cell, rates, seconds: float, seed: int):
     import jax
     from bench.lib import harness, result, stats, weights
     hf = cell.config
-    w = weights.make(hf, hf["weights"], seed)
+    w = weights.make(cell.reference.shapes(hf), hf["weights"], seed)
     jax.block_until_ready(w)
     for rate in rates:
         c = dataclasses.replace(cell, settings={**cell.settings,

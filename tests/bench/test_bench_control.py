@@ -17,9 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.lib import check, harness, weights
+from bench.lib import check, harness, spec, weights
 from bench.lib.traffic import Sampling
-from bench.reference import dense_gqa
 from repro.configs.base import ParallelPlan
 from repro.models import model as Mo
 from repro.models.env import Env
@@ -30,6 +29,7 @@ HF = dict(json.loads((Path(__file__).parent / "fixtures"
           vocab_size=2048, head_dim=64, num_attention_heads=4,
           num_key_value_heads=1)
 HF["weights"] = dict(HF["weights"], std=0.05)
+REF, ARCH = (spec.module(k, HF["reference"]) for k in ("reference", "arch"))
 ENV = Env(None, ParallelPlan(fsdp=False, remat="full", attn_impl="naive",
                              kv_cache="replicated"))
 S = 256
@@ -42,15 +42,14 @@ CHAT = Sampling(temperature=0.7, top_p=0.9, top_k=50, seed=0)
 def _logits(seed):
     """The reference's, the control's and the program's logits at every
     position of one random sequence."""
-    w = weights.make(HF, HF["weights"], seed)
-    mcfg = harness.model_config(HF)
-    params = harness.program_params(mcfg, HF, w)
+    w = weights.make(REF.shapes(HF), HF["weights"], seed)
+    mcfg = ARCH.model_config(HF)
+    params = harness.program_params(ARCH, mcfg, HF, w)
     toks = np.random.default_rng(seed).integers(
         0, HF["vocab_size"], size=(S,)).astype(np.int32)
-    hp = dense_gqa.hparams_of(HF)
-    ref = np.asarray(dense_gqa.logits_at(w, toks, np.arange(S), hp))
-    q = np.asarray(dense_gqa.logits_at(w, toks, np.arange(S), hp,
-                                       quant=True))
+    hp = REF.hparams_of(HF)
+    ref = np.asarray(REF.logits_at(w, toks, np.arange(S), hp))
+    q = np.asarray(REF.logits_at(w, toks, np.arange(S), hp, quant=True))
     prog, _, _ = Mo.forward(params, jnp.asarray(toks)[None], mcfg, ENV,
                             mode="train")
     prog = np.asarray(prog[0, :, :HF["vocab_size"]], np.float32)

@@ -1,7 +1,8 @@
 """A rehearsal of bench/run.py on the CPU at smoke size: the whole run
 but the chip check, with no device metric printed; the check catches a
-token altered where it is produced; the command refuses a CPU and a
-checkout without the program."""
+token altered where it is produced; a configuration of a new architecture
+is files only; the command refuses a CPU and a checkout without the
+program."""
 import json
 import os
 import shutil
@@ -20,12 +21,19 @@ CELL = "smoke.smoke-chat"
 SECONDS = 1.5
 
 
-@pytest.fixture(scope="module")
-def cell(tmp_path_factory):
-    root = tmp_path_factory.mktemp("checkout")
-    for d in ("configs", "traffic", "cells", "metrics"):
+def _checkout(root: Path, arch_key: str = "") -> spec.Cell:
+    """A checkout at root holding the smoke cell and its architecture's
+    modules. With arch_key, the smoke configuration names that key, and
+    the modules are copies of its own under that key: what a
+    configuration of a new architecture adds."""
+    for d in ("configs", "traffic", "cells", "metrics", "reference", "arch"):
         (root / "bench" / d).mkdir(parents=True)
-    shutil.copy(FIX / "smoke-config.json", root / "bench/configs/smoke.json")
+    hf = json.loads((FIX / "smoke-config.json").read_text())
+    for d in ("reference", "arch"):
+        shutil.copy(spec.BENCH / d / f"{hf['reference']}.py",
+                    root / "bench" / d / f"{arch_key or hf['reference']}.py")
+    hf["reference"] = arch_key or hf["reference"]
+    (root / "bench/configs/smoke.json").write_text(json.dumps(hf))
     shutil.copy(FIX / "smoke-chat.json", root / "bench/traffic/smoke-chat.json")
     (root / "bench/cells" / f"{CELL}.json").write_text(json.dumps(
         {"rate_rps": 20.0,
@@ -42,6 +50,11 @@ def cell(tmp_path_factory):
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
     return spec.find_cell(CELL, root=root, bench=bench)
+
+
+@pytest.fixture(scope="module")
+def cell(tmp_path_factory):
+    return _checkout(tmp_path_factory.mktemp("checkout"))
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +90,22 @@ def test_traced_rehearsal_reads_host_metrics_only(cell):
     assert "busy_s" not in out["device"] and out["correct"] is True
     assert 0 < out["metrics"]["prefix_hit_rate"]["value"] < 100
     assert 0 < out["metrics"]["kv_pool_peak_share"]["value"] <= 100
+
+
+def test_a_new_architecture_is_files_only(tmp_path, untraced):
+    """A copy of the smoke configuration's reference and arch modules
+    under a new key, and a configuration naming it, run the rehearsal
+    with no file of bench/lib changed, and report what the original
+    reports on the same seed."""
+    c = _checkout(tmp_path, "copied_arch")
+    assert Path(c.reference.__file__) == \
+        tmp_path / "bench/reference/copied_arch.py"
+    assert Path(c.arch.__file__) == tmp_path / "bench/arch/copied_arch.py"
+    out = result.run(c, 2**31 + 11, SECONDS, False, t_start=time.monotonic())
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] == untraced["attempted"]
+    assert set(out["metrics"]) == set(untraced["metrics"])
+    assert set(out["check"]) == set(untraced["check"])
 
 
 def _alter_tokens(monkeypatch, vocab: int, greedy: bool) -> None:

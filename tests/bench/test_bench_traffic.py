@@ -105,10 +105,11 @@ def test_arrivals_are_poisson_at_the_rate_after_the_backlog(mix):
 def test_a_new_mix_and_cell_load_from_files_alone(tmp_path):
     """A new mix and cell are added as files and BENCHMARK.json
     entries; the harness finds them by name."""
-    for d in ("configs", "traffic", "cells", "metrics"):
+    for d in ("configs", "traffic", "cells", "metrics", "reference", "arch"):
         (tmp_path / "bench" / d).mkdir(parents=True)
-    shutil.copy(spec.BENCH / "configs" / "qwen2-1.5b.json",
-                tmp_path / "bench" / "configs" / "qwen2-1.5b.json")
+    for f in ("configs/qwen2-1.5b.json", "reference/dense_gqa.py",
+              "arch/dense_gqa.py"):
+        shutil.copy(spec.BENCH / f, tmp_path / "bench" / f)
     mix = dict(MIXES["decode-unshared"], lead_in_s=3)
     mix["output"] = dict(mix["output"], median=64, min=16, max=128)
     (tmp_path / "bench" / "traffic" / "short-answers.json").write_text(
